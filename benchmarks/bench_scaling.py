@@ -30,7 +30,10 @@ from repro.core import mf
 def run_sharded():
     """shard/ suite: real multi-device steps/sec at 1, 2, 4, 8 *forced host*
     devices (one subprocess per count — the device split must precede the
-    first jax import, which this process already did).
+    first jax import, which this process already did).  The children always
+    run on the CPU (``JAX_PLATFORMS=cpu``, whatever the parent's platform),
+    so on a TPU host they never compete with the parent for the chip; every
+    row says ``platform=cpu``.
 
     ``shard_efficiency`` = steps/sec at S devices / steps/sec at 1.  The S
     forced devices share one CPU's silicon, so 1.0 means sharding (collective
@@ -42,7 +45,7 @@ def run_sharded():
         env = dict(os.environ)
         env["XLA_FLAGS"] = \
             f"--xla_force_host_platform_device_count={devices}"
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in ("src", env.get("PYTHONPATH", "")) if p)
         out = subprocess.run(
@@ -56,10 +59,10 @@ def run_sharded():
         rec = json.loads(out.stdout.strip().splitlines()[-1])
         sps[devices] = rec["steps_per_sec"]
         emit(f"shard/devices={devices}", rec["us_per_step"],
-             f"steps_per_sec={rec['steps_per_sec']:.1f}")
+             f"platform=cpu steps_per_sec={rec['steps_per_sec']:.1f}")
     emit("shard/shard_efficiency", 0.0,
-         f"shard_efficiency={sps[8] / sps[1]:.2f} "
-         "(8-dev vs 1-dev steps/sec on forced host devices; "
+         f"platform=cpu shard_efficiency={sps[8] / sps[1]:.2f} "
+         "(8-dev vs 1-dev steps/sec on forced host CPU devices; "
          "1.0 = sharding overhead-free, shared silicon)")
 
 

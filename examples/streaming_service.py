@@ -20,7 +20,6 @@ Two acts:
 import shutil
 import time
 
-import jax
 import numpy as np
 
 from repro.core import mf
@@ -103,7 +102,6 @@ def act_two_crash_resume(reference):
 
 
 def main():
-    jax.config.update("jax_platforms", "cpu")
     reference = act_one_freshness()
     act_two_crash_resume(reference)
 

@@ -75,7 +75,12 @@ def synth_cf_dataset(num_users: int, num_items: int, *, seed: int = 0,
                      interactions_per_user: int = 20, num_clusters: int = 16,
                      test_frac: float = 0.2) -> CFDataset:
     """Clustered power-law interactions: user u prefers items from its
-    cluster's popularity-ranked pool, making CF signal recoverable."""
+    cluster's popularity-ranked pool, making CF signal recoverable.
+
+    Each user takes ``min(interactions_per_user, pool size)`` distinct items
+    of its pool, drawn one after another with probability proportional to
+    1/rank among the items not yet drawn.  All users of a cluster draw
+    together (:func:`_distinct_power_law`), so 400k users take seconds."""
     rng = np.random.default_rng(seed)
     user_cluster = rng.integers(0, num_clusters, num_users)
     item_cluster = rng.integers(0, num_clusters, num_items)
@@ -86,16 +91,45 @@ def synth_cf_dataset(num_users: int, num_items: int, *, seed: int = 0,
     n_train = interactions_per_user - n_test
     train = np.full((num_users, n_train), -1, np.int32)
     test = np.full((num_users, n_test), -1, np.int32)
-    for u in range(num_users):
-        pool = pools[user_cluster[u]]
-        # power-law within the cluster pool
-        w = 1.0 / np.arange(1, len(pool) + 1)
-        w /= w.sum()
+    for c, pool in enumerate(pools):
+        users = np.flatnonzero(user_cluster == c)
         k = min(interactions_per_user, len(pool))
-        items = rng.choice(pool, size=k, replace=False, p=w)
-        train[u, :max(k - n_test, 0)] = items[:max(k - n_test, 0)]
-        test[u, :min(n_test, k)] = items[max(k - n_test, 0):k]
+        items = pool[_distinct_power_law(rng, len(pool), users.size, k)]
+        n_tr = max(k - n_test, 0)
+        train[users, :n_tr] = items[:, :n_tr]
+        test[users, :min(n_test, k)] = items[:, n_tr:k]
     return CFDataset(num_users, num_items, train, test)
+
+
+def _distinct_power_law(rng: np.random.Generator, pool_size: int, rows: int,
+                        k: int) -> np.ndarray:
+    """(rows, k) indices into a pool, distinct within each row, drawn one
+    after another with P(rank r) proportional to 1/(r+1) among the ranks not
+    yet drawn.  Each row draws with replacement and rejects repeats (which
+    is that successive sampling exactly); rows short of k distinct ranks
+    draw again."""
+    cdf = np.cumsum(1.0 / np.arange(1, pool_size + 1))
+    cdf /= cdf[-1]
+    out = np.empty((rows, k), np.int64)
+    todo = np.arange(rows)
+    draws = np.empty((rows, 0), np.int64)
+    while todo.size:
+        fresh = np.searchsorted(cdf, rng.random((todo.size, 2 * k)),
+                                side="right")
+        draws = np.concatenate([draws, np.minimum(fresh, pool_size - 1)],
+                               axis=1)
+        # first occurrence of each value in its row, in draw order
+        order = np.argsort(draws, axis=1, kind="stable")
+        ranked = np.take_along_axis(draws, order, axis=1)
+        new_sorted = np.ones(ranked.shape, bool)
+        new_sorted[:, 1:] = ranked[:, 1:] != ranked[:, :-1]
+        first = np.empty_like(new_sorted)
+        np.put_along_axis(first, order, new_sorted, axis=1)
+        done = first.sum(axis=1) >= k
+        keep = np.argsort(~first[done], axis=1, kind="stable")[:, :k]
+        out[todo[done]] = np.take_along_axis(draws[done], keep, axis=1)
+        todo, draws = todo[~done], draws[~done]
+    return out
 
 
 @dataclasses.dataclass(frozen=True)

@@ -54,14 +54,9 @@ def use_mesh(mesh: Optional[Mesh]):
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable shard_map: ``jax.shard_map`` (check_vma) on new jax,
-    ``jax.experimental.shard_map`` (check_rep) on older releases."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check)
+    """``jax.shard_map`` with ``check_vma`` off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def mesh_axes() -> frozenset[str]:

@@ -11,11 +11,18 @@ contraction runs on the MXU, and no normalized or concatenated intermediate is
 ever materialized in HBM.  A second kernel evaluates the fused backward from
 the cached statistics (the §4.4 reuse — no dot product is recomputed).
 
-Tiling: grid over batch tiles of ``block_b`` rows.  Per-step VMEM footprint is
-    block_b*K (u) + block_b*K (p) + block_b*n*K (negs) + outputs,
-e.g. 256*128*4B * (2 + 64) = 8.6 MiB for n=64 — comfortably inside VMEM.
-K and n should be multiples of 128 on real hardware (the MXU lane width); the
-wrappers in ops.py pad when they are not.
+Tiling: grid over batch tiles of ``block_b`` rows.  Blocks span the whole
+n and K axes, so any n and K lower; the wrappers in ops.py pad only the batch
+rows, to a multiple of ``block_b``.  The per-example (Bt, n, K) negatives
+block dominates VMEM: it is double-buffered on the way in, the backward
+double-buffers a same-sized gradient block on the way out, and the
+multiply-and-reduce temporaries are that size again.  ``per_example_block_b``
+therefore sizes the tile so one negatives block is at most 1 MiB (32 rows at
+n=64, K=128: about 8 MiB in all, inside the 16 MiB default scoped VMEM of a
+v5e).  The per-example contractions (u . n_j, sum_j w_j n_j) are
+multiply-and-reduce on the VPU: a batched ``dot_general`` with a 2-D operand
+that has no free dimension does not lower on the TPU, and each of those dots
+reads every negative once anyway, so they are bandwidth-bound either way.
 """
 from __future__ import annotations
 
@@ -34,18 +41,22 @@ def _stats_kernel(u_ref, p_ref, n_ref, uu_ref, pp_ref, up_ref, nn_ref, un_ref):
     pp_ref[...] = jnp.sum(p * p, axis=-1, keepdims=True)
     up_ref[...] = jnp.sum(u * p, axis=-1, keepdims=True)
     nn_ref[...] = jnp.sum(n * n, axis=-1)                      # (Bt, n)
-    # MXU contraction: un[b, j] = sum_k u[b, k] n[b, j, k]
-    un_ref[...] = jax.lax.dot_general(
-        u, n, dimension_numbers=(((1,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+    # un[b, j] = sum_k u[b, k] n[b, j, k]
+    un_ref[...] = jnp.sum(u[:, None, :] * n, axis=-1)
+
+
+def per_example_block_b(n: int, k: int) -> int:
+    """Batch rows per tile for the per-example kernels: the largest multiple
+    of 8 whose (rows, n, K) fp32 negatives block fits in 1 MiB (at least 8)."""
+    return max(8, (1 << 20) // (n * k * 4) // 8 * 8)
 
 
 def ccl_stats_pallas(user: jax.Array, pos: jax.Array, negs: jax.Array,
-                     *, block_b: int = 256, interpret: bool = False):
+                     *, block_b: int | None = None, interpret: bool = False):
     """user (B,K), pos (B,K), negs (B,n,K) -> (uu, pp, up) (B,1) and (nn, un) (B,n)."""
     b, k = user.shape
     n = negs.shape[1]
-    block_b = min(block_b, b)
+    block_b = min(block_b or per_example_block_b(n, k), b)
     grid = (pl.cdiv(b, block_b),)
     out_shape = [
         jax.ShapeDtypeStruct((b, 1), jnp.float32),   # uu
@@ -98,10 +109,8 @@ def _bwd_kernel(mu, theta, inv_n_negs,
     wn = d_ns * inv_u * inv_nn                              # (Bt, n)
 
     coeff_u = (wp * up + jnp.sum(wn * un, axis=-1, keepdims=True)) / uu
-    # du = wp*p + wn @ negs - coeff_u * u      (MXU for the (Bt,n)x(n,K) part)
-    wn_negs = jax.lax.dot_general(
-        wn, negs, dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+    # du = wp*p + sum_j wn_j negs_j - coeff_u * u
+    wn_negs = jnp.sum(wn[..., None] * negs, axis=1)
     du_ref[...] = (wp * p + wn_negs - coeff_u * u).astype(du_ref.dtype)
     dp_ref[...] = (wp * u - (wp * up / pp) * p).astype(dp_ref.dtype)
     dn_ref[...] = (wn[..., None] * u[:, None, :]
@@ -247,11 +256,11 @@ def ccl_bwd_shared_pallas(user, pos, negs, uu, pp, up, nn, un, w, g_scalar,
 
 def ccl_bwd_pallas(user, pos, negs, uu, pp, up, nn, un, g_scalar,
                    *, mu: float, theta: float,
-                   block_b: int = 256, interpret: bool = False):
+                   block_b: int | None = None, interpret: bool = False):
     """Fused backward tile kernel.  g_scalar: () cotangent already divided by B."""
     b, k = user.shape
     n = negs.shape[1]
-    block_b = min(block_b, b)
+    block_b = min(block_b or per_example_block_b(n, k), b)
     grid = (pl.cdiv(b, block_b),)
     vec_spec = pl.BlockSpec((block_b, k), lambda i: (i, 0))
     neg_spec = pl.BlockSpec((block_b, n, k), lambda i: (i, 0, 0))

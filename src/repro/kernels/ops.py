@@ -1,8 +1,9 @@
 """Jit-ready public wrappers around the Pallas kernels.
 
-Dispatch policy: on a TPU backend the kernels run compiled; everywhere else
-(this container is CPU-only) they run in ``interpret=True`` mode, which
-executes the kernel body in Python/XLA-CPU for correctness validation.
+Dispatch policy: on a TPU backend the kernels run compiled; on the CPU
+backend (tests, laptops) they run in ``interpret=True`` mode, which executes
+the kernel body on XLA-CPU for correctness validation.  Any other backend is
+an error, never a silent fall back to the interpreter.
 ``use_kernel=False`` falls back to the pure-jnp reference path (used both as
 the oracle and as the XLA-fusion baseline in benchmarks).
 """
@@ -19,6 +20,7 @@ from repro.kernels.ccl_similarity import (
     ccl_bwd_shared_pallas,
     ccl_stats_pallas,
     ccl_stats_shared_pallas,
+    per_example_block_b,
 )
 from repro.kernels.embedding_update import (
     gather_fma_rows,
@@ -31,8 +33,14 @@ EPS = 1e-12
 
 
 def default_interpret() -> bool:
-    """True when Pallas must run interpreted (no TPU backend present)."""
-    return jax.default_backend() != "tpu"
+    """True on the CPU backend (Pallas interpreted), False on a TPU (Pallas
+    compiled); any other backend raises."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+            f"the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def _pad_rows(x: jax.Array, target: int) -> jax.Array:
@@ -46,9 +54,16 @@ def _pad_rows(x: jax.Array, target: int) -> jax.Array:
 # Fused CCL loss: stats kernel forward + analytic Eq.4/5 backward kernel.
 # ----------------------------------------------------------------------------
 
+def _row_tile(block_b, user, negs):
+    """Batch rows per kernel tile: ``block_b`` or the VMEM-sized default."""
+    if block_b is None:
+        block_b = per_example_block_b(negs.shape[1], user.shape[1])
+    return min(block_b, user.shape[0])
+
+
 def _ccl_fwd(user, pos, negs, mu, theta, block_b, interpret):
     b = user.shape[0]
-    bb = min(block_b, b)
+    bb = _row_tile(block_b, user, negs)
     bp = ((b + bb - 1) // bb) * bb
     u_p, p_p, n_p = _pad_rows(user, bp), _pad_rows(pos, bp), _pad_rows(negs, bp)
     uu, pp, up, nn, un = ccl_stats_pallas(u_p, p_p, n_p, block_b=bb,
@@ -63,11 +78,13 @@ def _ccl_fwd(user, pos, negs, mu, theta, block_b, interpret):
 
 
 def make_ccl_loss_pallas(mu: float = 1.0, theta: float = 0.0,
-                         block_b: int = 256, interpret: bool | None = None):
+                         block_b: int | None = None,
+                         interpret: bool | None = None):
     """Factory returning a fused-CCL loss fn with kernel fwd+bwd.
 
     ``fn(user, pos, negs) -> scalar``; gradients flow to all three inputs via
-    the analytic backward kernel (residual reuse, §4.4).
+    the analytic backward kernel (residual reuse, §4.4).  ``block_b=None``
+    sizes the batch tile from n and K (``per_example_block_b``).
     """
     interp = default_interpret() if interpret is None else interpret
 
@@ -82,7 +99,7 @@ def make_ccl_loss_pallas(mu: float = 1.0, theta: float = 0.0,
 
     def bwd(saved, g):
         (u_p, p_p, n_p, uu, pp, up, nn, un), b = saved
-        bb = min(block_b, u_p.shape[0])
+        bb = _row_tile(block_b, u_p, n_p)
         g_row = (g / b).astype(jnp.float32)
         du, dp, dn = ccl_bwd_pallas(u_p, p_p, n_p, uu, pp, up, nn, un, g_row,
                                     mu=mu, theta=theta, block_b=bb,

@@ -28,7 +28,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro.compat import cost_analysis_dict as _cost_dict
 from repro.configs import ARCH_NAMES, get_config
 from repro.distributed import sharding as shd
 from repro.launch.mesh import make_production_mesh
@@ -98,7 +97,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, layers=None,
         t_compile = time.time() - t0 - t_lower
 
         mem = compiled.memory_analysis()
-        cost = _cost_dict(compiled)
+        cost = compiled.cost_analysis()
         coll = collective_bytes(compiled.as_text())
 
     record = {
@@ -141,7 +140,7 @@ def lower_mf_cell(shape_name: str, mesh, *, users=None, items=None):
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
-        cost = _cost_dict(compiled)
+        cost = compiled.cost_analysis()
         coll = collective_bytes(compiled.as_text())
     record = {
         "arch": "heat-mf-amazon", "shape": shape_name,

@@ -12,6 +12,15 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices):
+    """``jax.make_mesh`` with every axis ``Auto``: the repo places arrays
+    with ``with_sharding_constraint``/``NamedSharding``, which only accept
+    Auto axes (``make_mesh`` defaults to Explicit)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,14 +35,14 @@ def make_production_mesh(*, multi_pod: bool = False):
             "the dry-run entrypoint must set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import (see launch/dryrun.py)")
-    return jax.make_mesh(shape, axes, devices=devices[:ndev])
+    return _mesh(shape, axes, devices[:ndev])
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many local devices exist (tests / examples)."""
     ndev = data * model
     devices = jax.devices()[:ndev]
-    return jax.make_mesh((data, model), ("data", "model"), devices=devices)
+    return _mesh((data, model), ("data", "model"), devices)
 
 
 def make_data_mesh(data: int = 0):

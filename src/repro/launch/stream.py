@@ -152,4 +152,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch import enable_compile_cache
+    enable_compile_cache()
     main()

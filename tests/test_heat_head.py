@@ -148,7 +148,6 @@ def test_heat_head_cheaper_than_softmax_in_flops():
         hh, t, tab, jax.random.PRNGKey(0), cfg)[0]).lower(h, table).compile()
     soft = jax.jit(lambda hh, tab: full_softmax_loss(
         hh, t, tab)).lower(h, table).compile()
-    from repro.compat import cost_analysis_dict
-    f_heat = cost_analysis_dict(heat).get("flops", 0.0)
-    f_soft = cost_analysis_dict(soft).get("flops", 0.0)
+    f_heat = heat.cost_analysis().get("flops", 0.0)
+    f_soft = soft.cost_analysis().get("flops", 0.0)
     assert f_heat < f_soft / 10, (f_heat, f_soft)

@@ -40,7 +40,7 @@ def _batch(step: int, cfg: mf.MFConfig, b: int = 8) -> mf.Batch:
 
 # -- quantize/dequantize properties -----------------------------------------
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(seed=st.integers(0, 2 ** 16), rows=st.integers(1, 24),
        cols=st.integers(1, 48), mag_exp=st.integers(-6, 6))
 def test_roundtrip_error_bounded(seed, rows, cols, mag_exp):
@@ -52,7 +52,7 @@ def test_roundtrip_error_bounded(seed, rows, cols, mag_exp):
     assert np.all(np.abs(deq - np.asarray(x)) <= bound + 1e-6 * np.abs(deq))
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(rows=st.integers(1, 16), cols=st.integers(1, 32))
 def test_all_zero_rows_scale_floor(rows, cols):
     """absmax 0 must hit the scale floor, not divide by zero, and the rows
@@ -87,7 +87,7 @@ def test_near_overflow_absmax():
     assert np.all(np.abs(deq - np.asarray(x)) <= np.asarray(t.scale) * 0.51)
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(frac_pct=st.integers(0, 100), base=st.integers(-5, 5))
 def test_stochastic_round_unbiased(frac_pct, base):
     """E[floor(x + u)] == x: the empirical mean over many keys lands within

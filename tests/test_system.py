@@ -1,8 +1,11 @@
 """End-to-end system behaviour: the paper's full pipeline (data -> HEAT train
 -> evaluate -> serve) and the LM pipeline (train -> prefill -> decode)."""
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.core.metrics import evaluate_ranking, topk_exclude_train
@@ -57,3 +60,23 @@ def test_end_to_end_lm_train_then_serve():
         tok = jnp.argmax(logits_t[:, 0], -1)[:, None].astype(jnp.int32)
         assert logits_t.shape == (2, 1, cfg.vocab)
         assert np.isfinite(np.asarray(logits_t)).all()
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/cache"])
+def test_entry_point_compile_cache_placement(env_dir, monkeypatch):
+    """Entry points keep the compile cache where JAX_COMPILATION_CACHE_DIR
+    says (the helper then sets nothing), else at <checkout>/.jax_cache."""
+    from repro.launch import COMPILE_CACHE_DIR, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        enable_compile_cache()
+        got = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    repo = Path(__file__).resolve().parents[1]
+    assert COMPILE_CACHE_DIR == repo / ".jax_cache"
+    assert got == (before if env_dir else str(repo / ".jax_cache"))
