@@ -20,6 +20,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.analysis import tracing
 from repro.core import aggregation as agg
 from repro.core import samplers
 from repro.core.engine import SampleContext, StepEngine, resolve_engine
@@ -153,7 +154,8 @@ def heat_train_step(state: MFState, batch: Batch, rng: jax.Array, cfg: MFConfig,
     if engine is None:
         engine = resolve_engine(cfg)
     params, tile = state.params, state.tile
-    r_neg, r_tile = jax.random.split(rng)
+    with jax.named_scope(tracing.HEAT_SAMPLE):
+        r_neg, r_tile = jax.random.split(rng)
     # Int8 layout: gathered rows are dequantized (inside the Pallas kernel on
     # the pallas backend, as a fused gather-multiply otherwise) and the row
     # updates requantize with stochastic rounding.  The rounding keys derive
@@ -162,10 +164,11 @@ def heat_train_step(state: MFState, batch: Batch, rng: jax.Array, cfg: MFConfig,
     quantized = isinstance(params.user_table, qz.QuantizedTable)
     in_kernel = quantized and engine.backend == "pallas"
 
-    user_e = qz.gather_rows(params.user_table, batch.user_ids,
-                            use_kernel=in_kernel)
-    pos_e = qz.gather_rows(params.item_table, batch.pos_ids,
-                           use_kernel=in_kernel)
+    with jax.named_scope(tracing.HEAT_GATHER):
+        user_e = qz.gather_rows(params.user_table, batch.user_ids,
+                                use_kernel=in_kernel)
+        pos_e = qz.gather_rows(params.item_table, batch.pos_ids,
+                               use_kernel=in_kernel)
     n_shape = (batch.user_ids.shape[0], cfg.num_negatives)
 
     # Negative draw through the engine's sampler protocol: the context hands
@@ -174,25 +177,28 @@ def heat_train_step(state: MFState, batch: Batch, rng: jax.Array, cfg: MFConfig,
     # returned state (the protocol's slot for stateful strategies; shipped
     # samplers leave it untouched) — write-through coherence and the refresh
     # schedule stay below, after the gradient step.
-    drawn = engine.sampler.sample(
-        SampleContext(table=params.item_table, tile=tile,
-                      pos_ids=batch.pos_ids, weights=item_weights),
-        r_neg, n_shape)
+    with jax.named_scope(tracing.HEAT_SAMPLE):
+        drawn = engine.sampler.sample(
+            SampleContext(table=params.item_table, tile=tile,
+                          pos_ids=batch.pos_ids, weights=item_weights),
+            r_neg, n_shape)
     neg_ids, neg_e, neg_local = drawn.ids, drawn.embs, drawn.local_idx
     tile = drawn.state.tile
 
     hist_e = hist_mask = None
     if params.aggregator is not None:
-        hist_e = qz.gather_rows(params.item_table, batch.hist_ids,
-                                use_kernel=in_kernel)
-        hist_mask = batch.hist_mask.astype(user_e.dtype)
+        with jax.named_scope(tracing.HEAT_GATHER):
+            hist_e = qz.gather_rows(params.item_table, batch.hist_ids,
+                                    use_kernel=in_kernel)
+            hist_mask = batch.hist_mask.astype(user_e.dtype)
 
     def loss_fn(u, p, n, h, a):
         return _forward_loss(u, p, n, h, hist_mask, a, cfg, engine)
 
     argnums = (0, 1, 2) + ((3, 4) if params.aggregator is not None else ())
-    loss, grads = jax.value_and_grad(loss_fn, argnums=argnums)(
-        user_e, pos_e, neg_e, hist_e, params.aggregator)
+    with jax.named_scope(tracing.HEAT_CCL):     # the backward pass inherits it
+        loss, grads = jax.value_and_grad(loss_fn, argnums=argnums)(
+            user_e, pos_e, neg_e, hist_e, params.aggregator)
     g_user, g_pos, g_neg = grads[0], grads[1], grads[2]
 
     # Sharded execution (mf_distributed): forward/backward above is data-
@@ -205,51 +211,55 @@ def heat_train_step(state: MFState, batch: Batch, rng: jax.Array, cfg: MFConfig,
     # sharded carry tracks the single-device step to rounding.  The
     # step-shared/tile-sourced negative layouts are exactly the cheap case:
     # slot-reduction below shrinks their exchange from (B, n, K) to (N1, K).
-    ids_user, ids_pos = map(shd.replicated, (batch.user_ids, batch.pos_ids))
-    g_user, g_pos, g_neg = map(shd.replicated, (g_user, g_pos, g_neg))
-    neg_ids = shd.replicated(neg_ids)
-    neg_local = None if neg_local is None else shd.replicated(neg_local)
-    ids_hist = g_hist = None
-    if params.aggregator is not None:
-        ids_hist = shd.replicated(batch.hist_ids)
-        g_hist = shd.replicated(grads[3])
+    with jax.named_scope(tracing.HEAT_ROW_UPDATE):
+        ids_user, ids_pos = map(shd.replicated,
+                                (batch.user_ids, batch.pos_ids))
+        g_user, g_pos, g_neg = map(shd.replicated, (g_user, g_pos, g_neg))
+        neg_ids = shd.replicated(neg_ids)
+        neg_local = None if neg_local is None else shd.replicated(neg_local)
+        ids_hist = g_hist = None
+        if params.aggregator is not None:
+            ids_hist = shd.replicated(batch.hist_ids)
+            g_hist = shd.replicated(grads[3])
 
-    # §3.1/§4.3: only touched rows are written.  All of the step's item
-    # gradient groups go to row_update_many in ONE call: one XLA scatter for
-    # scatter_add, one cross-group pre-reduce + single gather-FMA kernel
-    # launch for pallas, one dense full-table write for the torch baseline
-    # of Table 1.  Scatter-add semantics everywhere, so ids duplicated within
-    # or across groups accumulate and concurrent-row updates cannot conflict.
-    # Tile-sourced negatives whose sample count exceeds the tile are
-    # slot-reduced at the sampler boundary first: the table then scatters N1
-    # unique rows instead of B*n duplicate-heavy ones, and the tile
-    # write-through becomes a dense add (the old per-group double scatter was
-    # what made large tiles slower than uniform sampling).  When the tile is
-    # *larger* than the sample (big N1, small batch) the reduction would
-    # inflate the table write from B*n to N1 rows, so the per-sample scatter
-    # path stays (shapes are static — the branch resolves at trace time).
-    if quantized:
-        new_user = qz.apply_updates(params.user_table, ids_user, g_user,
-                                    cfg.lr, jax.random.fold_in(rng, 1))
-    else:
-        new_user = engine.row_update(params.user_table, ids_user, g_user,
-                                     cfg.lr)
-    neg_reduced = None
-    item_groups = [(ids_pos, g_pos)]
-    if neg_local is not None and tile.tile_ids.shape[0] <= neg_local.size:
-        neg_reduced = samplers.reduce_local_grads(neg_local, g_neg,
-                                                  tile.tile_ids.shape[0])
-        item_groups.append((tile.tile_ids, neg_reduced))
-    else:
-        item_groups.append((neg_ids, g_neg))
-    if params.aggregator is not None:
-        item_groups.append((ids_hist, g_hist))
-    if quantized:
-        new_item = qz.apply_updates_many(params.item_table, item_groups,
-                                         cfg.lr, jax.random.fold_in(rng, 2))
-    else:
-        new_item = engine.row_update_many(params.item_table, item_groups,
-                                          cfg.lr)
+        # §3.1/§4.3: only touched rows are written.  All of the step's item
+        # gradient groups go to row_update_many in ONE call: one XLA scatter
+        # for scatter_add, one cross-group pre-reduce + single gather-FMA
+        # kernel launch for pallas, one dense full-table write for the torch
+        # baseline of Table 1.  Scatter-add semantics everywhere, so ids
+        # duplicated within or across groups accumulate and concurrent-row
+        # updates cannot conflict. Tile-sourced negatives whose sample count
+        # exceeds the tile are slot-reduced at the sampler boundary first: the
+        # table then scatters N1 unique rows instead of B*n duplicate-heavy
+        # ones, and the tile write-through becomes a dense add (the old
+        # per-group double scatter was what made large tiles slower than
+        # uniform sampling).  When the tile is *larger* than the sample (big
+        # N1, small batch) the reduction would inflate the table write from B*n
+        # to N1 rows, so the per-sample scatter path stays (shapes are static —
+        # the branch resolves at trace time).
+        if quantized:
+            new_user = qz.apply_updates(params.user_table, ids_user, g_user,
+                                        cfg.lr, jax.random.fold_in(rng, 1))
+        else:
+            new_user = engine.row_update(params.user_table, ids_user, g_user,
+                                         cfg.lr)
+        neg_reduced = None
+        item_groups = [(ids_pos, g_pos)]
+        if neg_local is not None and tile.tile_ids.shape[0] <= neg_local.size:
+            neg_reduced = samplers.reduce_local_grads(neg_local, g_neg,
+                                                      tile.tile_ids.shape[0])
+            item_groups.append((tile.tile_ids, neg_reduced))
+        else:
+            item_groups.append((neg_ids, g_neg))
+        if params.aggregator is not None:
+            item_groups.append((ids_hist, g_hist))
+        if quantized:
+            new_item = qz.apply_updates_many(params.item_table, item_groups,
+                                             cfg.lr,
+                                             jax.random.fold_in(rng, 2))
+        else:
+            new_item = engine.row_update_many(params.item_table, item_groups,
+                                              cfg.lr)
 
     # Tile coherence: write the same updates through to the replicated copy
     # (slot-reduced negatives as a dense add, small tile-sourced samples by
@@ -257,23 +267,29 @@ def heat_train_step(state: MFState, batch: Batch, rng: jax.Array, cfg: MFConfig,
     # history, uniform-sourced negatives — concatenated into ONE
     # sorted-intersection pass), then refresh on schedule (§4.2).
     if tile is not None:
-        global_groups = [(ids_pos, g_pos)]
-        if neg_reduced is not None:
-            tile = samplers.tile_apply_reduced(tile, neg_reduced, cfg.lr)
-        elif neg_local is not None:
-            tile = samplers.tile_apply_grads(tile, neg_local, g_neg, cfg.lr)
-        else:
-            global_groups.append((neg_ids, g_neg))
-        if params.aggregator is not None:
-            global_groups.append((ids_hist, g_hist))
-        tile = samplers.tile_apply_global_grads_many(tile, global_groups, cfg.lr)
-        tile = samplers.tile_refresh(tile, r_tile, new_item, cfg.refresh_interval)
+        with jax.named_scope(tracing.HEAT_TILE):
+            global_groups = [(ids_pos, g_pos)]
+            if neg_reduced is not None:
+                tile = samplers.tile_apply_reduced(tile, neg_reduced, cfg.lr)
+            elif neg_local is not None:
+                tile = samplers.tile_apply_grads(tile, neg_local, g_neg,
+                                                 cfg.lr)
+            else:
+                global_groups.append((neg_ids, g_neg))
+            if params.aggregator is not None:
+                global_groups.append((ids_hist, g_hist))
+            tile = samplers.tile_apply_global_grads_many(tile, global_groups,
+                                                         cfg.lr)
+            tile = samplers.tile_refresh(tile, r_tile, new_item,
+                                         cfg.refresh_interval)
 
     # Aggregator: local accumulation, deferred flush (§4.5 / Listing 1).
     aggregator, accum = params.aggregator, state.accum
     if aggregator is not None:
-        accum = agg.accumulate(accum, grads[4])
-        aggregator, accum = agg.maybe_flush(accum, aggregator, cfg.lr, cfg.flush_every)
+        with jax.named_scope(tracing.HEAT_ROW_UPDATE):
+            accum = agg.accumulate(accum, grads[4])
+            aggregator, accum = agg.maybe_flush(accum, aggregator, cfg.lr,
+                                                cfg.flush_every)
 
     new_state = MFState(
         params=MFParams(new_user, new_item, aggregator),
@@ -303,8 +319,9 @@ def make_scan_body(cfg: MFConfig, batch_fn, seed: int, *,
     base = jax.random.PRNGKey(seed)
 
     def body(state: MFState, step: jax.Array):
-        batch = batch_fn(step)
-        rng = jax.random.fold_in(base, step)
+        with jax.named_scope(tracing.HEAT_BATCH):
+            batch = batch_fn(step)
+            rng = jax.random.fold_in(base, step)
         return heat_train_step(state, batch, rng, cfg, engine=engine,
                                item_weights=item_weights)
 
@@ -358,39 +375,48 @@ def topk_all_items(params: MFParams, user_ids: jax.Array, k: int, *,
     but never duplicated).  ``k > num_items`` is clamped: the result is
     (B, min(k, I)) — every item ranked, no phantom ids.
     """
-    u = qz.gather_rows(params.user_table, user_ids)
     t = params.item_table
     num_items = qz.num_rows(t)
     k = min(int(k), num_items)
     c = item_chunk or num_items
     if c >= num_items:
-        sc = _score_item_block(u, qz.dequantize_table(t), similarity)
-        if exclude_mask is not None:
-            sc = jnp.where(exclude_mask, -jnp.inf, sc)
-        return jax.lax.top_k(sc, k)[1]
+        with jax.named_scope(tracing.TOPK_PREPARE):
+            u = qz.gather_rows(params.user_table, user_ids)
+        with jax.named_scope(tracing.TOPK_SCORE):
+            sc = _score_item_block(u, qz.dequantize_table(t), similarity)
+            if exclude_mask is not None:
+                sc = jnp.where(exclude_mask, -jnp.inf, sc)
+        with jax.named_scope(tracing.TOPK_MERGE):
+            return jax.lax.top_k(sc, k)[1]
 
     num_chunks = -(-num_items // c)
     pad = num_chunks * c - num_items
-    t_p = qz.pad_rows(t, pad)
-    mask_p = (jnp.pad(exclude_mask, ((0, 0), (0, pad)), constant_values=True)
-              if exclude_mask is not None else None)
+    with jax.named_scope(tracing.TOPK_PREPARE):
+        u = qz.gather_rows(params.user_table, user_ids)
+        t_p = qz.pad_rows(t, pad)
+        mask_p = (jnp.pad(exclude_mask, ((0, 0), (0, pad)),
+                          constant_values=True)
+                  if exclude_mask is not None else None)
     b = u.shape[0]
 
     def body(i, carry):
         best_s, best_i = carry
         s0 = i * c
-        block = qz.dynamic_slice_rows(t_p, s0, c)
-        sc = _score_item_block(u, block, similarity)
-        ids = s0 + jnp.arange(c, dtype=jnp.int32)
-        dead = ids[None, :] >= num_items                 # padding rows
-        if mask_p is not None:
-            dead = dead | jax.lax.dynamic_slice_in_dim(mask_p, s0, c, axis=1)
-        sc = jnp.where(dead, -jnp.inf, sc.astype(best_s.dtype))
-        cat_s = jnp.concatenate([best_s, sc], axis=1)
-        cat_i = jnp.concatenate([best_i, jnp.broadcast_to(ids[None, :],
-                                                          sc.shape)], axis=1)
-        best_s, idx = jax.lax.top_k(cat_s, k)
-        return best_s, jnp.take_along_axis(cat_i, idx, axis=1)
+        with jax.named_scope(tracing.TOPK_SCORE):
+            block = qz.dynamic_slice_rows(t_p, s0, c)
+            sc = _score_item_block(u, block, similarity)
+            ids = s0 + jnp.arange(c, dtype=jnp.int32)
+            dead = ids[None, :] >= num_items             # padding rows
+            if mask_p is not None:
+                dead = dead | jax.lax.dynamic_slice_in_dim(mask_p, s0, c,
+                                                           axis=1)
+            sc = jnp.where(dead, -jnp.inf, sc.astype(best_s.dtype))
+        with jax.named_scope(tracing.TOPK_MERGE):
+            cat_s = jnp.concatenate([best_s, sc], axis=1)
+            cat_i = jnp.concatenate(
+                [best_i, jnp.broadcast_to(ids[None, :], sc.shape)], axis=1)
+            best_s, idx = jax.lax.top_k(cat_s, k)
+            return best_s, jnp.take_along_axis(cat_i, idx, axis=1)
 
     _, best_i = jax.lax.fori_loop(
         0, num_chunks, body,

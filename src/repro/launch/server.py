@@ -13,6 +13,11 @@ Python->XLA dispatch and an under-filled matmul.  The
   * every device call is padded to exactly ``max_batch`` rows, so there is
     ONE compiled program regardless of fill level — no shape-driven
     retraces in steady state (asserted by the trace counter);
+  * each queued request's wait, from its enqueue until its batch closes,
+    goes into a fixed log-spaced histogram (:data:`QUEUE_WAIT_EDGES_S`) that
+    :attr:`BatchingRecommender.stats` exposes with its count and sum, so
+    any percentile over an interval is read from two snapshots
+    (:func:`histogram_quantile`);
   * the compiled program takes the embedding tables (and the retrieval
     index) as *arguments*, not closed-over constants, so
     :meth:`refresh_from` swaps in an online trainer's updated ``MFState``
@@ -24,6 +29,7 @@ the first real request pays serving latency, not compilation latency.
 """
 from __future__ import annotations
 
+import bisect
 import queue
 import threading
 import time
@@ -33,16 +39,47 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis import tracing
 from repro.analysis.sanitize import TraceCounter
 from repro.core import mf
 from repro.core import retrieval as rtv
 from repro.optim import quantization as qz
 
 
+#: Bin edges (s) of the queue-wait histogram: 156 log-spaced edges from
+#: 50 us to 120 s, a ratio of 1.0994 between neighbours.  Bin i holds waits
+#: in [edges[i-1], edges[i]); bin 0 those under the first edge, the last bin
+#: those of the last edge or more.
+QUEUE_WAIT_EDGES_S = tuple(float(e) for e in np.geomspace(50e-6, 120.0, 156))
+
+
+def histogram_quantile(counts, q: float,
+                       edges=QUEUE_WAIT_EDGES_S) -> Optional[float]:
+    """The ``q`` quantile (0..1) of a histogram over ``edges``, such as the
+    difference of two ``stats["queue_wait_hist"]`` snapshots: interpolated
+    geometrically inside the bin that holds it, clamped to the first and
+    last edge.  None for an empty histogram."""
+    total = sum(counts)
+    if total <= 0:
+        return None
+    rank, seen = q * total, 0
+    for i, n in enumerate(counts):
+        if n and seen + n >= rank:
+            if i == 0:
+                return edges[0]
+            if i == len(edges):
+                return edges[-1]
+            frac = (rank - seen) / n
+            return edges[i - 1] * (edges[i] / edges[i - 1]) ** frac
+        seen += n
+    return edges[-1]
+
+
 class _Request(NamedTuple):
     user_id: int
     event: threading.Event
     result: list           # single-slot box the worker fills
+    enqueued: float        # time.perf_counter() at the enqueue
 
 
 class BatchingRecommender:
@@ -84,6 +121,9 @@ class BatchingRecommender:
         self.trace_counter = TraceCounter("batching_recommender", budget=1)
         self._device_calls = 0
         self._requests_served = 0
+        self._counts_lock = threading.Lock()
+        self._wait_hist = [0] * (len(QUEUE_WAIT_EDGES_S) + 1)
+        self._wait_sum_s = 0.0
         self._log = log or (lambda *_: None)
         # degraded-serving health: a failed refresh keeps the previous
         # snapshot live and is *counted*, never swallowed silently
@@ -128,18 +168,24 @@ class BatchingRecommender:
 
     # -- device path -------------------------------------------------------
 
-    def _call(self, user_ids: jax.Array) -> np.ndarray:
-        out = self._fn(self._params, self._index, user_ids)
+    def _call(self, user_ids) -> np.ndarray:
+        """One device call over up to ``max_batch`` host user ids, padded
+        to ``max_batch`` rows; returns the (max_batch, k) host answer."""
+        with tracing.span(tracing.SERVE_DISPATCH):
+            padded = np.zeros(self.max_batch, np.int32)
+            padded[:len(user_ids)] = user_ids
+            out = self._fn(self._params, self._index, jnp.asarray(padded))
         self._device_calls += 1
         self.trace_counter.check()      # steady-state retrace = hard failure
-        return np.asarray(jax.block_until_ready(out))
+        with tracing.span(tracing.SERVE_READBACK):
+            return np.asarray(jax.block_until_ready(out))
 
     def warmup(self) -> float:
         """Trace + compile the serving path on a dummy full batch; returns
         the wall seconds spent, which the first real request then does NOT
         pay (tests assert the second call does not retrace)."""
         t0 = time.perf_counter()
-        self._call(jnp.zeros((self.max_batch,), jnp.int32))
+        self._call(())
         return time.perf_counter() - t0
 
     @property
@@ -148,10 +194,17 @@ class BatchingRecommender:
 
     @property
     def stats(self) -> dict:
-        return {"device_calls": self._device_calls,
-                "requests_served": self._requests_served,
-                "traces": self.trace_counter.count,
-                **self.health}
+        """Counters since construction.  ``queue_wait_*`` cover requests
+        that came through the queue (:meth:`recommend`): their count, the
+        sum of their waits in seconds, and the histogram of the waits over
+        :data:`QUEUE_WAIT_EDGES_S`."""
+        with self._counts_lock:
+            counts = {"device_calls": self._device_calls,
+                      "requests_served": self._requests_served,
+                      "queue_wait_count": sum(self._wait_hist),
+                      "queue_wait_sum_s": self._wait_sum_s,
+                      "queue_wait_hist": list(self._wait_hist)}
+        return {**counts, "traces": self.trace_counter.count, **self.health}
 
     @property
     def health(self) -> dict:
@@ -172,10 +225,9 @@ class BatchingRecommender:
         outs = []
         for s in range(0, ids.size, self.max_batch):
             chunk = ids[s:s + self.max_batch]
-            padded = np.zeros(self.max_batch, np.int32)
-            padded[:chunk.size] = chunk
-            outs.append(self._call(jnp.asarray(padded))[:chunk.size])
-        self._requests_served += ids.size
+            outs.append(self._call(chunk)[:chunk.size])
+        with self._counts_lock:
+            self._requests_served += ids.size
         return np.concatenate(outs, axis=0)
 
     # -- queue front-end ---------------------------------------------------
@@ -184,7 +236,8 @@ class BatchingRecommender:
                   ) -> np.ndarray:
         """Single-user entry point: enqueue and wait.  Concurrent callers
         are coalesced by the worker into one device call."""
-        req = _Request(int(user_id), threading.Event(), [None])
+        req = _Request(int(user_id), threading.Event(), [None],
+                       time.perf_counter())
         self._queue.put(req)
         if not req.event.wait(timeout):
             raise TimeoutError(f"recommend({user_id}) timed out")
@@ -194,39 +247,48 @@ class BatchingRecommender:
         return res
 
     def _serve_loop(self) -> None:
-        while True:
+        stopping = False
+        while not stopping:
             req = self._queue.get()
             if req is None:
                 return
-            batch = [req]
-            deadline = time.monotonic() + self.max_wait_ms / 1e3
-            while len(batch) < self.max_batch:
-                wait = deadline - time.monotonic()
-                if wait <= 0:
-                    break
-                try:
-                    nxt = self._queue.get(timeout=wait)
-                except queue.Empty:
-                    break
-                if nxt is None:
-                    self._flush(batch)
-                    return
-                batch.append(nxt)
-            self._flush(batch)
+            with tracing.span(tracing.SERVE_COLLECT):
+                batch = [req]
+                deadline = time.monotonic() + self.max_wait_ms / 1e3
+                while len(batch) < self.max_batch:
+                    wait = deadline - time.monotonic()
+                    if wait <= 0:
+                        break
+                    try:
+                        nxt = self._queue.get(timeout=wait)
+                    except queue.Empty:
+                        break
+                    if nxt is None:
+                        stopping = True
+                        break
+                    batch.append(nxt)
+                closed = time.perf_counter()
+            self._flush(batch, closed)
 
-    def _flush(self, batch: list) -> None:
-        padded = np.zeros(self.max_batch, np.int32)
-        padded[:len(batch)] = [r.user_id for r in batch]
+    def _flush(self, batch: list, closed: float) -> None:
+        """Serve one batch closed at ``closed`` (``time.perf_counter``)
+        and count each request's wait from its enqueue until then."""
         try:
-            out = self._call(jnp.asarray(padded))
-            for i, r in enumerate(batch):
-                r.result[0] = out[i]
+            out = self._call([r.user_id for r in batch])
+            answers = list(out[:len(batch)])
         except Exception as e:  # noqa: BLE001 — surfaced to the waiters
-            for r in batch:
-                r.result[0] = e
-        self._requests_served += len(batch)
-        for r in batch:
-            r.event.set()
+            answers = [e] * len(batch)
+        with tracing.span(tracing.SERVE_FANOUT):
+            waits = [closed - r.enqueued for r in batch]
+            with self._counts_lock:       # counted before any caller wakes
+                self._requests_served += len(batch)
+                for w in waits:
+                    self._wait_hist[bisect.bisect_right(QUEUE_WAIT_EDGES_S,
+                                                        w)] += 1
+                self._wait_sum_s += sum(waits)
+            for r, a in zip(batch, answers):
+                r.result[0] = a
+                r.event.set()
 
     # -- online refresh ----------------------------------------------------
 

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis import tracing
 from repro.analysis.sanitize import TraceCounter
 from repro.core import mf, samplers
 from repro.core import mf_distributed as mfd
@@ -193,7 +194,9 @@ class EpochExecutor:
         The start index goes up via ``jax.device_put`` (an *explicit*
         transfer): ``jnp.asarray(start)`` counts as implicit and would trip
         ``repro.analysis.sanitize``'s transfer guard on every dispatch."""
-        return self._compiled(length)(state, jax.device_put(np.int32(start)))
+        fn = self._compiled(length)
+        with tracing.span(tracing.TRAIN_DISPATCH):
+            return fn(state, jax.device_put(np.int32(start)))
 
 
 def _window_length(step: int, stop: int, k: int, ckpt_every: int,
@@ -219,7 +222,9 @@ def run_window(executor: EpochExecutor, state, step: int, stop: int,
     length = _window_length(step, stop, executor.steps_per_dispatch,
                             ckpt_every, fail_at_step)
     state, window = executor.run(state, step, length)
-    return state, np.asarray(window), length
+    with tracing.span(tracing.TRAIN_READBACK):
+        losses = np.asarray(window)
+    return state, losses, length
 
 
 _run_window = run_window        # internal callers predate the public name

@@ -1,6 +1,8 @@
 """BatchingRecommender (launch/server.py): warmup/no-retrace contract,
 request coalescing, batched-vs-direct parity, and online refresh_from."""
+import bisect
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -8,7 +10,8 @@ import numpy as np
 import pytest
 
 from repro.core import mf, retrieval
-from repro.launch.server import BatchingRecommender
+from repro.launch.server import (QUEUE_WAIT_EDGES_S, BatchingRecommender,
+                                 histogram_quantile)
 
 USERS, ITEMS, DIM, K = 64, 200, 16, 10
 
@@ -155,3 +158,77 @@ def test_constructor_validates_args():
         BatchingRecommender(state, K, pruner="annoy")
     with pytest.raises(ValueError):
         BatchingRecommender(state, K, pruner="tile")   # tile needs an index
+
+
+def test_queue_wait_counts_every_queued_request():
+    """Each request that came through the queue is counted once in the
+    queue-wait histogram, with a positive sum of waits."""
+    state = _state()
+    with BatchingRecommender(state, K, max_batch=8,
+                             max_wait_ms=5.0) as server:
+        before = server.stats
+        threads = [threading.Thread(target=server.recommend, args=(uid,))
+                   for uid in range(24)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads)
+        stats = server.stats
+    assert stats["queue_wait_count"] == stats["requests_served"] == 24
+    assert sum(stats["queue_wait_hist"]) == 24
+    assert len(stats["queue_wait_hist"]) == len(QUEUE_WAIT_EDGES_S) + 1
+    assert stats["queue_wait_sum_s"] > 0
+    assert before["queue_wait_count"] == 0
+    for key in ("device_calls", "requests_served", "traces", "status",
+                "refreshes", "refresh_failures", "stale_refreshes",
+                "last_refresh_error"):
+        assert key in stats                       # the existing keys stay
+
+
+def test_queue_wait_covers_a_call_in_flight():
+    """Requests that queue behind a device call in flight wait for it and
+    then for the batching deadline: the histogram's median is at least
+    ``max_wait_ms``."""
+    state = _state()
+    max_wait_ms = 20.0
+    with BatchingRecommender(state, K, max_batch=32,
+                             max_wait_ms=max_wait_ms) as server:
+        fast = server._fn
+        started = threading.Event()
+
+        def slow(*args):
+            started.set()
+            time.sleep(0.2)
+            return fast(*args)
+
+        server._fn = slow
+        first = threading.Thread(target=server.recommend, args=(0,))
+        first.start()
+        assert started.wait(timeout=30)          # the first call is in flight
+        queued = [threading.Thread(target=server.recommend, args=(uid,))
+                  for uid in range(1, 16)]
+        for t in queued:
+            t.start()
+        for t in [first] + queued:
+            t.join(timeout=30)
+        stats = server.stats
+    assert stats["queue_wait_count"] == 16
+    median_s = histogram_quantile(stats["queue_wait_hist"], 0.5)
+    assert median_s >= max_wait_ms / 1e3
+    assert stats["queue_wait_sum_s"] / 16 >= max_wait_ms / 1e3
+
+
+@pytest.mark.parametrize("waits_s,q,lo,hi", [
+    ([0.11] * 9, 0.5, 0.11 / 1.1, 0.11 * 1.1),
+    ([1e-6, 1e-6, 3.0], 0.5, 0, QUEUE_WAIT_EDGES_S[0]),
+    ([500.0], 0.99, QUEUE_WAIT_EDGES_S[-1], QUEUE_WAIT_EDGES_S[-1]),
+])
+def test_histogram_quantile_lies_in_the_bin_of_the_wait(waits_s, q, lo, hi):
+    counts = [0] * (len(QUEUE_WAIT_EDGES_S) + 1)
+    for w in waits_s:
+        counts[bisect.bisect_right(QUEUE_WAIT_EDGES_S, w)] += 1
+    assert lo <= histogram_quantile(counts, q) <= hi
+    assert histogram_quantile([0] * len(counts), q) is None
+    ratios = np.diff(np.log(QUEUE_WAIT_EDGES_S))
+    assert np.all(ratios <= np.log(1.1))
