@@ -16,7 +16,9 @@ One chip, in order:
 4. one window with int8 tables, whose compiled window must hold the
    gather-dequant kernel;
 5. top-10 serving through ``BatchingRecommender`` on the trained tables,
-   answering concurrent requests, checked against ``mf.topk_all_items``.
+   answering concurrent requests through the top-k scan kernel, checked
+   against ``mf.topk_all_items`` over the whole catalog at once; prints the
+   chunks the scan merged out of those it scanned.
 
 Four chips: the default-engine run on a 4-way ``data`` mesh and on a
 2 x 2 (``data``, ``model``) mesh, compared with a one-device run.
@@ -227,6 +229,8 @@ def phase_serve(state):
     """Concurrent top-k requests through the batching server."""
     import jax.numpy as jnp
     from repro.core import mf
+    from repro.kernels import ops
+    from repro.kernels.topk_scan import chunk_width
     from repro.launch.server import BatchingRecommender
     num_users = state.params.user_table.shape[0]
     users = np.linspace(0, num_users - 1, REQUESTS).astype(np.int32)
@@ -251,6 +255,13 @@ def phase_serve(state):
           "server top-k differs from mf.topk_all_items")
     check(stats["traces"] == 1 and stats["status"] == "ok",
           f"server stats {stats}")
+    items = state.params.item_table
+    u = state.params.user_table[jnp.asarray(users[:SERVE_BATCH])]
+    _, merged = ops.topk_scan(u, items, None, TOPK, similarity="cosine",
+                              item_chunk=ITEM_CHUNK)
+    chunks = -(-items.shape[0] // chunk_width(ITEM_CHUNK))
+    say(f"serve: the top-k scan merged {int(merged)} of {chunks} chunks "
+        f"({int(merged) / chunks:.2%}) for {SERVE_BATCH} users")
 
 
 def run_one_chip(cfg, cfg_pallas, ds) -> None:

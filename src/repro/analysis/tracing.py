@@ -20,13 +20,12 @@ HEAT_SAMPLE = "heat.sample"            # the negative sampler's draw and gather
 HEAT_CCL = "heat.ccl"                  # loss forward and backward
 HEAT_ROW_UPDATE = "heat.row_update"    # user/item row updates, slot reduction
 HEAT_TILE = "heat.tile"                # tile write-through and refresh
-TOPK_PREPARE = "topk.prepare"          # user gather, item-table padding
-TOPK_SCORE = "topk.score"              # a chunk's dequantize, norms, scores
-TOPK_MERGE = "topk.merge"              # the running top-k merge
+TOPK_PREPARE = "topk.prepare"          # user gather and dequantize
+TOPK_SCAN = "topk.scan"                # scoring and the running top-k
 
 HEAT_SCOPES = (HEAT_BATCH, HEAT_GATHER, HEAT_SAMPLE, HEAT_CCL,
                HEAT_ROW_UPDATE, HEAT_TILE)
-TOPK_SCOPES = (TOPK_PREPARE, TOPK_SCORE, TOPK_MERGE)
+TOPK_SCOPES = (TOPK_PREPARE, TOPK_SCAN)
 
 # -- host spans ----------------------------------------------------------------
 TRAIN_DISPATCH = "train.dispatch"      # EpochExecutor.run: start up, enqueue

@@ -365,60 +365,32 @@ def topk_all_items(params: MFParams, user_ids: jax.Array, k: int, *,
                    exclude_mask: Optional[jax.Array] = None) -> jax.Array:
     """Top-k item ids per user over the full catalog, chunked.
 
-    A running (B, k) top-k is merged with each (B, item_chunk) score block
-    inside a ``lax.fori_loop``, so the full (B, I) score matrix is **never
-    materialized** and the compiled program is O(1) in the chunk count — the
-    serving / full-catalog-evaluation path for paper-scale item counts (9.4M
-    items at Table 3 scale would be a 38 GB score matrix for a 1k-user
-    batch, and ~18k chunks must not unroll into the HLO).  ``exclude_mask``
-    (B, I) bool masks training positives (sliced per chunk, so it is read
-    but never duplicated).  ``k > num_items`` is clamped: the result is
-    (B, min(k, I)) — every item ranked, no phantom ids.
+    With ``item_chunk`` below the catalog size, one Pallas kernel
+    (``kernels/topk_scan.py``) streams the item table and keeps a running
+    (B, k) top-k in on-chip memory, merging a (B, item_chunk) score block
+    (``item_chunk`` rounded down to whole 128-lane tiles, 128 to 512) only
+    when one of its scores beats its row's k-th.  So the full (B, I) score matrix is **never materialized**
+    and the compiled program is O(1) in the chunk count — the serving /
+    full-catalog-evaluation path for paper-scale item counts (9.4M items at
+    Table 3 scale would be a 38 GB score matrix for a 1k-user batch).
+    ``exclude_mask`` (B, I) bool masks training positives (read per block).
+    Ties go to the lowest item id.  ``k > num_items`` is clamped: the
+    result is (B, min(k, I)) — every item ranked, no phantom ids.
     """
     t = params.item_table
     num_items = qz.num_rows(t)
     k = min(int(k), num_items)
     c = item_chunk or num_items
-    if c >= num_items:
-        with jax.named_scope(tracing.TOPK_PREPARE):
-            u = qz.gather_rows(params.user_table, user_ids)
-        with jax.named_scope(tracing.TOPK_SCORE):
+    with jax.named_scope(tracing.TOPK_PREPARE):
+        u = qz.gather_rows(params.user_table, user_ids)
+    with jax.named_scope(tracing.TOPK_SCAN):
+        if c >= num_items:
             sc = _score_item_block(u, qz.dequantize_table(t), similarity)
             if exclude_mask is not None:
                 sc = jnp.where(exclude_mask, -jnp.inf, sc)
-        with jax.named_scope(tracing.TOPK_MERGE):
             return jax.lax.top_k(sc, k)[1]
-
-    num_chunks = -(-num_items // c)
-    pad = num_chunks * c - num_items
-    with jax.named_scope(tracing.TOPK_PREPARE):
-        u = qz.gather_rows(params.user_table, user_ids)
-        t_p = qz.pad_rows(t, pad)
-        mask_p = (jnp.pad(exclude_mask, ((0, 0), (0, pad)),
-                          constant_values=True)
-                  if exclude_mask is not None else None)
-    b = u.shape[0]
-
-    def body(i, carry):
-        best_s, best_i = carry
-        s0 = i * c
-        with jax.named_scope(tracing.TOPK_SCORE):
-            block = qz.dynamic_slice_rows(t_p, s0, c)
-            sc = _score_item_block(u, block, similarity)
-            ids = s0 + jnp.arange(c, dtype=jnp.int32)
-            dead = ids[None, :] >= num_items             # padding rows
-            if mask_p is not None:
-                dead = dead | jax.lax.dynamic_slice_in_dim(mask_p, s0, c,
-                                                           axis=1)
-            sc = jnp.where(dead, -jnp.inf, sc.astype(best_s.dtype))
-        with jax.named_scope(tracing.TOPK_MERGE):
-            cat_s = jnp.concatenate([best_s, sc], axis=1)
-            cat_i = jnp.concatenate(
-                [best_i, jnp.broadcast_to(ids[None, :], sc.shape)], axis=1)
-            best_s, idx = jax.lax.top_k(cat_s, k)
-            return best_s, jnp.take_along_axis(cat_i, idx, axis=1)
-
-    _, best_i = jax.lax.fori_loop(
-        0, num_chunks, body,
-        (jnp.full((b, k), -jnp.inf, u.dtype), jnp.zeros((b, k), jnp.int32)))
-    return best_i
+        from repro.kernels import ops
+        q, scale = ((t.q, t.scale) if isinstance(t, qz.QuantizedTable)
+                    else (t, None))
+        return ops.topk_scan(u, q, scale, k, similarity=similarity,
+                             item_chunk=c, exclude_mask=exclude_mask)[0]

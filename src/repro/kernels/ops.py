@@ -28,6 +28,7 @@ from repro.kernels.embedding_update import (
     reset_launch_count,
 )
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.topk_scan import topk_scan_pallas
 
 EPS = 1e-12
 
@@ -219,6 +220,25 @@ def fused_rows_update(table: jax.Array, groups, lr, *, use_kernel: bool = True,
     grads = jnp.concatenate([g.reshape(-1, g.shape[-1]) for _, g in groups])
     return sparse_row_update(table, ids, grads, lr, use_kernel=use_kernel,
                              interpret=interpret)
+
+
+# ----------------------------------------------------------------------------
+# Exact full-catalog top-k: one kernel streams the item table.
+# ----------------------------------------------------------------------------
+
+def topk_scan(u: jax.Array, items: jax.Array, scale, k: int, *,
+              similarity: str, item_chunk: int, exclude_mask=None,
+              interpret: bool | None = None):
+    """Top-k item ids of the (B, K) user rows ``u`` over an int8 payload
+    ``items`` with its (I, 1) ``scale``, or a float table with ``scale``
+    None, scored in chunks of ``topk_scan.chunk_width(item_chunk)`` items.
+    Returns the (B, k) ids and the number of chunks merged into the
+    running top-k (kernels/topk_scan.py); ``ref.topk_scan_ref`` is the
+    oracle."""
+    interp = default_interpret() if interpret is None else interpret
+    return topk_scan_pallas(u, items, scale, exclude_mask, k=k,
+                            similarity=similarity, item_chunk=item_chunk,
+                            interpret=interp)
 
 
 # ----------------------------------------------------------------------------

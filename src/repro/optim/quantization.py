@@ -155,32 +155,6 @@ def slice_rows(table: Table, start: int, stop: int) -> jax.Array:
     return (table.q[start:stop].astype(jnp.float32) * table.scale[start:stop])
 
 
-def pad_rows(table: Table, pad: int) -> Table:
-    """Zero-pad ``pad`` extra rows (quantized zeros dequantize to zeros) —
-    the chunked-top-k helper."""
-    if pad == 0:
-        return table
-    if not isinstance(table, QuantizedTable):
-        return jnp.pad(table, ((0, pad), (0, 0)))
-    return QuantizedTable(
-        q=jnp.pad(table.q, ((0, pad), (0, 0))),
-        scale=jnp.pad(table.scale, ((0, pad), (0, 0)),
-                      constant_values=SCALE_FLOOR),
-        err=jnp.pad(table.err, ((0, pad), (0, 0))),
-        err_scale=jnp.pad(table.err_scale, ((0, pad), (0, 0)),
-                          constant_values=SCALE_FLOOR))
-
-
-def dynamic_slice_rows(table: Table, start, count: int) -> jax.Array:
-    """``lax.dynamic_slice_in_dim`` over rows, dequantized — the in-loop
-    chunk read of ``mf.topk_all_items`` (start may be traced)."""
-    if not isinstance(table, QuantizedTable):
-        return jax.lax.dynamic_slice_in_dim(table, start, count, axis=0)
-    q = jax.lax.dynamic_slice_in_dim(table.q, start, count, axis=0)
-    s = jax.lax.dynamic_slice_in_dim(table.scale, start, count, axis=0)
-    return q.astype(jnp.float32) * s
-
-
 def table_spec(tree):
     """Hashable (treedef, leaf (shape, dtype) tuple) of a table pytree —
     what a compiled serving program is keyed on.  Distinguishes fp32 from

@@ -178,12 +178,8 @@ def test_accessors_match_fp32_semantics():
     assert qz.num_rows(t) == qz.num_rows(x) == 20
     assert qz.logical_dtype(t) == jnp.float32
     assert np.asarray(qz.slice_rows(t, 4, 9)).shape == (5, 8)
-    padded = qz.pad_rows(t, 4)
-    assert qz.num_rows(padded) == 24
-    assert np.all(np.asarray(qz.dequantize_rows(
-        padded, jnp.arange(20, 24))) == 0.0)
-    dyn = np.asarray(qz.dynamic_slice_rows(t, jnp.int32(2), 6))
-    assert np.array_equal(dyn, np.asarray(qz.slice_rows(t, 2, 8)))
+    assert np.array_equal(np.asarray(qz.slice_rows(t, 2, 8)),
+                          np.asarray(qz.dequantize_rows(t, jnp.arange(2, 8))))
 
 
 def test_table_bytes_halved():

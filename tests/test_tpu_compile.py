@@ -5,7 +5,8 @@ described ``v5e:2x2`` topology.  Interpret mode (tests/test_kernels.py)
 cannot show what the chip's compiler refuses — unaligned block shapes,
 dot layouts Mosaic does not lower, VMEM overflow — and these compiles do.
 Shapes are the ``MF_100M`` smoke shape: batch 1024, 64 negatives, K=128,
-400k table rows.  One whole HEAT training window is compiled at that shape
+400k table rows; the top-k scan at the serving call's 32 users and
+512-item chunks.  One whole HEAT training window is compiled at that shape
 too, to show that the device scopes of ``repro.analysis.tracing`` survive
 the chip compiler's fusion.
 
@@ -23,8 +24,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ccl_similarity as ccl
 from repro.kernels import embedding_update as emb
+from repro.kernels import topk_scan as ts
 
 B, N, K, R = 1024, 64, 128, 400_000
+SERVE_B, ITEM_CHUNK = 32, 512            # BatchingRecommender's serving call
 
 
 @pytest.fixture(scope="module")
@@ -79,6 +82,16 @@ CASES = {
     "gather_dequant_rows": (
         lambda q, s, i: emb.gather_dequant_rows(q, s, i),
         [((R, K), jnp.int8), ((R, 1), F32), ((B,), I32)]),
+    # the serving call's full-catalog top-10 over int8 and fp32 items
+    "topk_scan_int8": (
+        lambda u, q, s: ts.topk_scan_pallas(
+            u, q, s, None, k=10, similarity="cosine", item_chunk=ITEM_CHUNK),
+        [((SERVE_B, K), F32), ((R, K), jnp.int8), ((R, 1), F32)]),
+    "topk_scan_fp32": (
+        lambda u, t: ts.topk_scan_pallas(
+            u, t, None, None, k=10, similarity="cosine",
+            item_chunk=ITEM_CHUNK),
+        [((SERVE_B, K), F32), ((R, K), F32)]),
 }
 
 
